@@ -164,8 +164,8 @@ def bench_paged_attention(quick: bool):
     bucket = 1 << (live_blocks - 1).bit_length()
 
     q = jnp.asarray(rng.normal(size=(slots, h, d)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(num_blocks, bs, kvh, d)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(num_blocks, bs, kvh, d)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(num_blocks, kvh, bs, d)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(num_blocks, kvh, bs, d)), jnp.float32)
     table = np.zeros((slots, full_blocks), np.int32)
     free = list(range(1, num_blocks))
     rng.shuffle(free)

@@ -42,12 +42,14 @@ import numpy as np
 
 import jax
 
-from repro.configs.archs import get_config
 from repro.launch.mesh import ensure_host_devices, parse_mesh_spec
+from repro.launch.runtime import use_repo_dirs
+from repro.launch.serve import model_config
 from repro.models import lm
 from repro.models.config import GRAUConfig
 from repro.serve.engine import EngineConfig, Request, ServeEngine
 from repro.serve.sampling import SamplingParams
+from repro.serve.sharding import init_params
 from repro.serve.telemetry import percentiles
 
 
@@ -1128,6 +1130,10 @@ def bench_recovery(cfg, params, args):
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-3b")
+    ap.add_argument("--published", action="store_true",
+                    help="the published config (default: its smoke variant)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to the first N layers")
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-seq", type=int, default=128)
@@ -1230,8 +1236,9 @@ def main() -> None:
     mesh_shape = parse_mesh_spec(args.mesh) if args.mesh else None
     if mesh_shape:
         ensure_host_devices(mesh_shape[0] * mesh_shape[1])
+    use_repo_dirs()
 
-    base_cfg = get_config(args.arch, smoke=True)
+    base_cfg = model_config(args.arch, args.published, args.layers)
     report = {
         "arch": base_cfg.name,
         "slots": args.slots,
@@ -1249,8 +1256,7 @@ def main() -> None:
     if "runs" in sections:
         for act_name, cfg in (("float", base_cfg),
                               ("grau", base_cfg.replace(grau=GRAUConfig()))):
-            params, _ = lm.init_lm(cfg, jax.random.PRNGKey(0),
-                                   dtype=jax.numpy.float32)
+            params = init_params(cfg, jax.random.PRNGKey(0))
             for samp_name, sampling in samplers.items():
                 engine = ServeEngine(
                     cfg, params,
@@ -1270,8 +1276,7 @@ def main() -> None:
                       f"{stats['recompiles_after_warmup']} recompiles]",
                       flush=True)
 
-    params, _ = lm.init_lm(base_cfg, jax.random.PRNGKey(0),
-                           dtype=jax.numpy.float32)
+    params = init_params(base_cfg, jax.random.PRNGKey(0))
     if "decode_scaling" in sections:
         report["decode_scaling"] = bench_decode_scaling(base_cfg, params,
                                                         args)

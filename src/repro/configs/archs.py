@@ -6,6 +6,8 @@ family (MoE stays MoE, hybrid stays hybrid) at toy scale for CPU tests.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 from repro.models.config import (EncoderConfig, GRAUConfig, ModelConfig,
                                  VisionStub, dense_groups, jamba_groups,
                                  moe_groups, ssm_groups)
@@ -303,6 +305,30 @@ ARCHS = {
 }
 
 
-def get_config(arch: str, *, smoke: bool = False) -> ModelConfig:
+def get_config(arch: str, *, smoke: bool = False,
+               layers: Optional[int] = None) -> ModelConfig:
+    """The published (or smoke) config; `layers` cuts the depth to the
+    first N layers — one pipeline stage's share of a deeper deployment.
+    Widths, heads and vocabulary are never cut."""
     full, small = ARCHS[arch]
-    return small() if smoke else full()
+    cfg = small() if smoke else full()
+    return cfg if layers is None else cut_depth(cfg, layers)
+
+
+def cut_depth(cfg: ModelConfig, layers: int) -> ModelConfig:
+    """Keep the first `layers` layers, whole periods only."""
+    if not 0 < layers <= cfg.num_layers:
+        raise ValueError(f"{cfg.name}: layers={layers} outside "
+                         f"1..{cfg.num_layers}")
+    groups, left = [], layers
+    for period, repeats in cfg.groups:
+        take = min(repeats, left // len(period))
+        if take:
+            groups.append((period, take))
+        left -= take * len(period)
+        if take < repeats:
+            break
+    if left:
+        raise ValueError(f"{cfg.name}: layers={layers} does not end on a "
+                         "whole layer period")
+    return cfg.replace(groups=tuple(groups))

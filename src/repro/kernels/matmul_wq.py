@@ -8,7 +8,9 @@ shift-only scaling applied to the decode bandwidth's dominant term.
 
 Grid: (M/bm, N/bn, K/tile), K innermost, one grid step per pack tile.  Each
 K step DMAs the tile's packed payload block plus its ``(1, bn)`` exponent
-row (one signed byte per (tile, out-channel)); 2^e is *constructed* by
+row (one signed byte per (tile, out-channel)); the exponent plane is viewed
+as (k_tiles, 1, N) so that row's block is legal on the TPU (its last two
+dims are (1, bn) of a (1, N) plane).  2^e is *constructed* by
 bitcast (quant/pot.exp2i) — never the approximate ``exp2`` — so the kernel,
 the jnp oracle (kernels/ref.matmul_wq_ref) and the dense fallback
 (quant/weights.dense) dequantize bit-identically.  Accumulation is f32 in a
@@ -33,7 +35,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 from repro.kernels.grau import grau_datapath
 from repro.pwlf.spec import MAX_SEGMENTS
 from repro.quant.pot import exp2i
@@ -42,17 +43,17 @@ DEFAULT_TILES = (256, 256)   # (bm, bn); the K tile is the pack tile
 
 
 def _dequant_w_block(w_ref, e_ref, bits: int) -> jax.Array:
-    """Packed (t_p, bn) payload + (1, bn) exponent row -> f32 (tile, bn).
+    """Packed (t_p, bn) payload + (1, 1, bn) exponent row -> f32 (tile, bn).
 
     Same split-halves discipline as quant/pot.unpack_int4, along the sublane
     axis: rows [0, t/2) are sign-extended low nibbles (tile elements
     0..t/2-1), rows [t/2, t) the high nibbles.  2^e comes from exp2i's
     bitcast construction, so the dequant is an exact exponent add.
     """
-    q = w_ref[...]
+    q = w_ref[...].astype(jnp.int32)      # Mosaic has no int8 shifts
     if bits == 4:
-        q = jnp.concatenate([(q << 4) >> 4, q >> 4], axis=0)
-    return q.astype(jnp.float32) * exp2i(e_ref[...])
+        q = jnp.concatenate([(q << 28) >> 28, q >> 4], axis=0)
+    return q.astype(jnp.float32) * exp2i(e_ref[0])
 
 
 def _mm_wq_kernel(*refs, bits, k_steps, fuse, num_exponents, qmin, qmax,
@@ -145,13 +146,14 @@ def matmul_wq_pallas(
         in_specs=reg_specs + [
             pl.BlockSpec((bm, tile), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((t_p, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((1, bn), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((1, 1, bn), lambda i, j, kk: (kk, 0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        name="matmul_wq",
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-    )(*reg_args, x.astype(jnp.float32), qw, e)
+    )(*reg_args, x.astype(jnp.float32), qw, e.reshape(k_tiles, 1, n))

@@ -22,16 +22,16 @@ def _dense_kv_views(k_pool, v_pool, block_table, *, k_exp=None, v_exp=None,
     fallback, and the kernel's in-VMEM dequant all read identical f32 values.
     """
     rows, nblocks = block_table.shape
-    block_size, kvh = k_pool.shape[1], k_pool.shape[2]
+    kvh, block_size = k_pool.shape[1], k_pool.shape[2]
     seq = nblocks * block_size
     if kv_bits < 16:
-        hd = k_pool.shape[3] * (2 if kv_bits == 4 else 1)
         kd = kvq.load_block(k_pool[block_table], k_exp[block_table], kv_bits)
         vd = kvq.load_block(v_pool[block_table], v_exp[block_table], kv_bits)
     else:
-        hd = k_pool.shape[3]
         kd, vd = k_pool[block_table], v_pool[block_table]
-    return (kd.reshape(rows, seq, kvh, hd), vd.reshape(rows, seq, kvh, hd))
+    # (rows, nblocks, kvh, bs, hd) -> (rows, seq, kvh, hd)
+    return tuple(x.transpose(0, 1, 3, 2, 4).reshape(rows, seq, kvh, -1)
+                 for x in (kd, vd))
 
 
 def _out_dtype(spec: GRAUSpec):
@@ -82,7 +82,7 @@ def matmul_wq_ref(x: jax.Array, w, spec: Optional[GRAUSpec] = None,
 
 def paged_attention_ref(
     q: jax.Array,             # (slots, h, d)
-    k_pool: jax.Array,        # (num_blocks, block_size, kvh, d)
+    k_pool: jax.Array,        # (num_blocks, kvh, block_size, d)
     v_pool: jax.Array,
     block_table: jax.Array,   # (slots, nblocks) int32
     lengths: jax.Array,       # (slots,) int32 — attended positions per slot
@@ -99,7 +99,7 @@ def paged_attention_ref(
     packed quantized pools dequantize through the same quant/kv helpers),
     run masked softmax attention, optionally apply the GRAU output epilogue."""
     slots, h, d = q.shape
-    block_size, kvh = k_pool.shape[1], k_pool.shape[2]
+    kvh, block_size = k_pool.shape[1], k_pool.shape[2]
     nblocks = block_table.shape[1]
     g = h // kvh
     scale = scale if scale is not None else d ** -0.5
@@ -122,7 +122,7 @@ def paged_attention_ref(
 
 def paged_prefill_ref(
     q: jax.Array,             # (b, C, h, d) — one prefill chunk per row
-    k_pool: jax.Array,        # (num_blocks, block_size, kvh, d)
+    k_pool: jax.Array,        # (num_blocks, kvh, block_size, d)
     v_pool: jax.Array,
     block_table: jax.Array,   # (b, nblocks) int32
     start: jax.Array,         # (b,) int32 — absolute position of chunk row 0
@@ -139,7 +139,7 @@ def paged_prefill_ref(
     packed pools via quant/kv), then run masked softmax attention where
     chunk row r attends positions 0..start+r."""
     b, chunk, h, d = q.shape
-    block_size, kvh = k_pool.shape[1], k_pool.shape[2]
+    kvh, block_size = k_pool.shape[1], k_pool.shape[2]
     nblocks = block_table.shape[1]
     g = h // kvh
     scale = scale if scale is not None else d ** -0.5

@@ -25,7 +25,7 @@ import jax
 from repro.configs.archs import ARCHS, get_config
 from repro.configs.shapes import SHAPES, cell_status
 from repro.launch import steps as steps_lib
-from repro.launch.mesh import make_production_mesh, named_shardings, use_mesh
+from repro.launch.mesh import make_production_mesh, named_shardings
 
 OUT_DIR = pathlib.Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
 
@@ -43,7 +43,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
 
     t0 = time.time()
     bundle = steps_lib.build_cell(cfg, shape, mesh, **overrides)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(bundle.fn,
                          in_shardings=named_shardings(mesh,
                                                       bundle.in_shardings),

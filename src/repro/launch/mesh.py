@@ -1,4 +1,4 @@
-"""Production meshes + version-tolerant mesh context / sharding helpers.
+"""Production, host and serving meshes + sharding helpers.
 
 Single pod: (data=16, model=16) — 256 chips (TPU v5e pod).
 Multi-pod:  (pod=2, data=16, model=16) — 512 chips; the `pod` axis is an outer
@@ -17,19 +17,27 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
+    """``jax.make_mesh`` with Auto axes.  The model code shards through
+    GSPMD propagation plus shard_ctx constraints, not through sharding in
+    types, which jax.make_mesh's default Explicit axes would turn on."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever this host actually has (smoke tests / examples): (1, N)."""
     n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"))
+    return make_mesh((1, n), ("data", "model"))
 
 
 def make_serve_mesh(data: int = 1, model: int = 1,
@@ -81,21 +89,9 @@ def data_axes(mesh) -> tuple:
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
 
-def use_mesh(mesh):
-    """Version-tolerant mesh context: `jax.set_mesh` was introduced after
-    0.4.x; older releases use the Mesh object itself as the context manager.
-    Either way, NamedShardings built from `mesh` work inside the block."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
 def named_shardings(mesh, spec_tree):
-    """PartitionSpec tree -> NamedSharding tree.
-
-    jax.jit on 0.4.x only accepts Sharding instances for in_shardings (bare
-    PartitionSpecs need the post-set_mesh API), so cell builders hand their
-    spec trees through this before jitting. is_leaf guards against
-    PartitionSpec being a tuple subclass on old releases."""
+    """PartitionSpec tree -> NamedSharding tree (for device_put and jit
+    shardings outside a `jax.set_mesh` context). is_leaf keeps each
+    PartitionSpec whole."""
     return jax.tree.map(lambda s: NamedSharding(mesh, s), spec_tree,
                         is_leaf=lambda x: isinstance(x, PartitionSpec))

@@ -8,6 +8,10 @@ Example:
       --temperature 0.8 --top-p 0.9 --policy prefill
   PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-3b --mesh 1x4
     (on CPU, forces 4 host devices automatically; see docs/sharding.md)
+  PYTHONPATH=src python -m repro.launch.serve --arch glm4-9b --published \
+      --layers 4 --max-seq 1024
+    (on a TPU: published widths, depth cut to the first 4 of 40 layers —
+     one pipeline stage's share; random weights from a fixed seed)
   PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-3b \
       --prefix-cache --shared-prefix 48 --prefill-chunk 32
     (radix-tree shared-prefix KV reuse + chunked prefill; --shared-prefix
@@ -25,13 +29,88 @@ from __future__ import annotations
 import argparse
 import json
 import signal
+import sys
+from typing import Callable, List, Optional
 
 import numpy as np
 
 
-def main() -> None:
+def serve_requests(engine, requests, stop: Optional[Callable[[], int]] = None
+                   ) -> List:
+    """Submit `requests` and tick `engine` until every one has retired;
+    returns them in completion order.  The launcher's serving loop —
+    chip_smoke.py drives the same one.  `stop()` (optional) says how many
+    shutdown signals have arrived: the first drains in-flight streams
+    (queued requests stay queued for a snapshot), the second stops now."""
+    for req in requests:
+        engine.submit(req)
+    done = []
+    draining = False
+    ticks = 0
+    while (engine.scheduler.waiting
+           or any(r is not None for r in engine.slot_req)):
+        signals = stop() if stop is not None else 0
+        if signals and not draining:
+            engine.begin_draining("signal")
+            draining = True
+            print("draining: finishing in-flight streams "
+                  "(signal again to stop now)")
+        if signals > 1:
+            break
+        if draining and all(r is None for r in engine.slot_req):
+            break       # in-flight done; queued wait in the snapshot
+        made_progress = (engine.step() > 0
+                         or not engine.scheduler.waiting)
+        done.extend(engine.poll())
+        ticks += 1
+        if ticks >= 100000:
+            break
+        if not made_progress and not any(r is not None
+                                         for r in engine.slot_req):
+            break       # queue head can never admit — bail, don't spin
+    done.extend(engine.poll())
+    return done
+
+
+def serving_failures(engine) -> List[str]:
+    """What makes a serving run fail: requests that fault containment
+    retired as internal_error (with the exceptions the engine kept), and an
+    engine left degraded.  Empty when the run is sound."""
+    from repro.serve.engine import DEGRADED
+    out = []
+    bad = [rs.rid for rs in engine.scheduler.finished
+           if rs.finish_reason == "internal_error"]
+    if bad:
+        errors = sorted({e["error"] for e in engine.errors})
+        out.append(f"{len(bad)} request(s) retired as internal_error "
+                   f"(rids {bad[:8]}): {errors}")
+    if engine.health == DEGRADED:
+        out.append(f"engine degraded: {engine.health_reason}")
+    return out
+
+
+def model_config(arch: str, published: bool, layers: Optional[int]):
+    """The config `--published` / `--layers` select, announced with its
+    widths and depth cut (the launcher and the serving bench share it)."""
+    from repro.configs.archs import get_config
+    cfg = get_config(arch, smoke=not published, layers=layers)
+    full = get_config(arch, smoke=not published)
+    print(f"model: {cfg.name} ({'published' if published else 'smoke'}"
+          f" widths: d_model={cfg.d_model}, heads={cfg.num_heads}/"
+          f"{cfg.num_kv_heads} kv, d_ff={cfg.d_ff}, vocab={cfg.vocab_size});"
+          f" layers {cfg.num_layers} of {full.num_layers}", flush=True)
+    return cfg
+
+
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-3b")
+    ap.add_argument("--published", action="store_true",
+                    help="serve the published config (default: its smoke "
+                         "variant, toy widths for the CPU)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to the first N layers (widths stay "
+                         "whole): one pipeline stage's share of the model")
     ap.add_argument("--mesh", default=None,
                     help="serve sharded: 'M' (tensor-parallel) or 'DxM'")
     ap.add_argument("--requests", type=int, default=6)
@@ -112,21 +191,24 @@ def main() -> None:
         ap.error("--resume requires --journal")
 
     from repro.launch.mesh import ensure_host_devices, parse_mesh_spec
+    from repro.launch.runtime import use_repo_dirs
     mesh_shape = parse_mesh_spec(args.mesh) if args.mesh else None
     if mesh_shape:
         ensure_host_devices(mesh_shape[0] * mesh_shape[1])
+    use_repo_dirs()
 
     import jax
 
-    from repro.configs.archs import get_config
     from repro.launch.mesh import make_serve_mesh
-    from repro.models import lm
     from repro.serve.engine import EngineConfig, Request, ServeEngine
     from repro.serve.sampling import SamplingParams
+    from repro.serve.sharding import init_params
 
     mesh = make_serve_mesh(*mesh_shape) if mesh_shape else None
-    cfg = get_config(args.arch, smoke=True)
-    params, _ = lm.init_lm(cfg, jax.random.PRNGKey(0), dtype=jax.numpy.float32)
+    cfg = model_config(args.arch, args.published, args.layers)
+    # built straight into the serving placement: under a mesh no device
+    # ever holds the whole tree
+    params = init_params(cfg, jax.random.PRNGKey(0), mesh=mesh)
     paged = None if args.backend == "auto" else (args.backend == "paged")
     telemetry = not args.no_telemetry
     if args.metrics_port is not None and not telemetry:
@@ -248,32 +330,7 @@ def main() -> None:
         signal.signal(sig, _on_signal)
 
     try:
-        for req in reqs:
-            engine.submit(req)
-        done = []
-        draining = False
-        ticks = 0
-        while (engine.scheduler.waiting
-               or any(r is not None for r in engine.slot_req)):
-            if signals["count"] and not draining:
-                engine.begin_draining("signal")
-                draining = True
-                print("draining: finishing in-flight streams "
-                      "(signal again to stop now)")
-            if signals["count"] > 1:
-                break
-            if draining and all(r is None for r in engine.slot_req):
-                break       # in-flight done; queued wait in the snapshot
-            made_progress = (engine.step() > 0
-                             or not engine.scheduler.waiting)
-            done.extend(engine.poll())
-            ticks += 1
-            if ticks >= 100000:
-                break
-            if not made_progress and not any(r is not None
-                                             for r in engine.slot_req):
-                break       # queue head can never admit — bail, don't spin
-        done.extend(engine.poll())
+        done = serve_requests(engine, reqs, stop=lambda: signals["count"])
         for r in done:
             print(f"req {r.rid}: prompt={len(r.prompt)} toks -> "
                   f"generated {len(r.out_tokens or [])}: "
@@ -293,9 +350,13 @@ def main() -> None:
             live = len(engine.scheduler.waiting)
             print(f"snapshot: {path} ({live} undelivered requests "
                   "captured)")
+        failures = serving_failures(engine)
     finally:
         engine.close()
+    for f in failures:
+        print(f"FAILED: {f}", file=sys.stderr)
+    return 1 if failures else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -20,7 +20,8 @@ from repro.configs.archs import get_config
 from repro.configs.shapes import SHAPES, ShapeSpec
 from repro.data.pipeline import make_lm_batch_for
 from repro.launch import steps as steps_lib
-from repro.launch.mesh import make_host_mesh, make_production_mesh, use_mesh
+from repro.launch.mesh import make_host_mesh, make_production_mesh
+from repro.launch.runtime import use_repo_dirs
 from repro.models import lm
 from repro.train import optim
 from repro.train.loop import LoopConfig, run
@@ -41,6 +42,7 @@ def main() -> None:
                     help="train with the GRAU activation surrogate")
     ap.add_argument("--lr", type=float, default=3e-3)
     args = ap.parse_args()
+    use_repo_dirs()
 
     cfg = get_config(args.arch, smoke=args.host)
     if args.grau:
@@ -67,7 +69,7 @@ def main() -> None:
     params, _ = lm.init_lm(cfg, jax.random.PRNGKey(0), dtype=dtype)
     opt_state = optim.init_opt_state(params)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(step_fn, donate_argnums=(0, 1))
         params, opt_state, hist = run(
             train_step=jitted,

@@ -55,16 +55,22 @@ def init_lm(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16):
         blocks.init_norm(enc_pb, "ln_enc", cfg.d_model, cfg.norm)
 
     for gi, (period, repeats) in enumerate(cfg.groups):
-        reps_params, axes = [], None
         gkey = pb._next()
-        for r in range(repeats):
-            lp = ParamBuilder(jax.random.fold_in(gkey, r), dtype)
+        axes = {}
+
+        def init_repeat(k, period=period, axes=axes):
+            lp = ParamBuilder(k, dtype)
             for li, spec in enumerate(period):
-                sub = lp.sub(f"l{li}")
-                blocks.init_layer(sub, spec, cfg)
-            reps_params.append(lp.params)
-            axes = lp.axes
-        pb.params[f"group{gi}"] = stack_params(reps_params)
+                blocks.init_layer(lp.sub(f"l{li}"), spec, cfg)
+            axes.update(lp.axes)
+            return lp.params
+
+        # one vmapped init per group, not `repeats` unrolled copies: the same
+        # keys give the same values, and a deep model's init program
+        # compiles as fast as a one-layer one
+        keys = jax.vmap(lambda r: jax.random.fold_in(gkey, r))(
+            jnp.arange(repeats))
+        pb.params[f"group{gi}"] = jax.vmap(init_repeat)(keys)
         pb.axes[f"group{gi}"] = stack_axes(axes)
 
     return pb.params, pb.axes

@@ -191,7 +191,7 @@ class PagedKVCache(NamedTuple):
     reserved as the null/trash block: unmapped block-table entries point at it,
     so writes from idle slots or padded prefill blocks land there harmlessly.
     """
-    k: jax.Array          # (num_blocks, block_size, kv_heads, head_dim)
+    k: jax.Array          # (num_blocks, kv_heads, block_size, head_dim)
     v: jax.Array
 
 
@@ -214,7 +214,7 @@ class QuantPagedKVCache:
     (with a rounding requantization shift of the resident payload) by decode
     writes — both in the shared jnp update paths below, never by a reader.
     """
-    k: jax.Array        # (num_blocks, block_size, kvh, packed_hd) int8
+    k: jax.Array        # (num_blocks, kvh, block_size, packed_hd) int8
     v: jax.Array
     k_exp: jax.Array    # (num_blocks, kvh) int8 power-of-two scale exponents
     v_exp: jax.Array
@@ -254,7 +254,7 @@ def _quant_paged_update(cache: QuantPagedKVCache, k_new, v_new,
     schedule that issues the same writes (the cache-on/off invariant).
     """
     bits = cache.bits
-    block_size = cache.k.shape[1]
+    block_size = cache.k.shape[2]
     blk = jnp.take_along_axis(
         st.block_table, (st.length // block_size)[:, None], axis=1)[:, 0]
     off = st.length % block_size
@@ -265,13 +265,13 @@ def _quant_paged_update(cache: QuantPagedKVCache, k_new, v_new,
         e_old = exp[blk]
         e_new = jnp.maximum(e_old, e_tok)
         delta = e_new.astype(jnp.int32) - e_old.astype(jnp.int32)
-        resident = buf[blk]                       # (slots, bs, kvh, hdp)
+        resident = buf[blk]                       # (slots, kvh, bs, hdp)
         q = kvq.unpack_int4(resident) if bits == 4 else resident
-        q = kvq.requant_shift(q, delta[:, None, :, None], bits)
+        q = kvq.requant_shift(q, delta[:, :, None, None], bits)
         qtok = kvq.quantize_pot(new, e_new[..., None], bits)
         q = jax.vmap(
-            lambda qb, qt, o: jax.lax.dynamic_update_slice(qb, qt[None],
-                                                           (o, 0, 0))
+            lambda qb, qt, o: jax.lax.dynamic_update_slice(qb, qt[:, None],
+                                                           (0, o, 0))
         )(q, qtok, off)
         payload = kvq.pack_int4(q) if bits == 4 else q
         return buf.at[blk].set(payload), exp.at[blk].set(e_new)
@@ -286,13 +286,13 @@ def paged_update(cache: AnyPagedKVCache, k_new: jax.Array, v_new: jax.Array,
     """Write one position per slot at logical index `length` via the table."""
     if isinstance(cache, QuantPagedKVCache):
         return _quant_paged_update(cache, k_new, v_new, st)
-    block_size = cache.k.shape[1]
+    block_size = cache.k.shape[2]
     blk = jnp.take_along_axis(
         st.block_table, (st.length // block_size)[:, None], axis=1)[:, 0]
     off = st.length % block_size
     return PagedKVCache(
-        k=cache.k.at[blk, off].set(k_new[:, 0].astype(cache.k.dtype)),
-        v=cache.v.at[blk, off].set(v_new[:, 0].astype(cache.v.dtype)),
+        k=cache.k.at[blk, :, off].set(k_new[:, 0].astype(cache.k.dtype)),
+        v=cache.v.at[blk, :, off].set(v_new[:, 0].astype(cache.v.dtype)),
     )
 
 
@@ -320,35 +320,23 @@ def paged_view(cache: AnyPagedKVCache, st: PagedState,
     table = (st.block_table if max_blocks is None
              else st.block_table[:, :max_blocks])
     slots, blocks_per_slot = table.shape
-    block_size = cache.k.shape[1]
-    kvh, hd = cache.k.shape[2], cache.k.shape[3]
+    kvh, block_size = cache.k.shape[1], cache.k.shape[2]
     seq = blocks_per_slot * block_size
+    quantized = isinstance(cache, QuantPagedKVCache)
 
-    if isinstance(cache, QuantPagedKVCache):
-        bits = cache.bits
-        hd = hd * 2 if bits == 4 else hd
-
-        def qview(pool, exp):
-            packed = pool[table]                  # (slots, nbl, bs, kvh, hdp)
-            e = exp[table]                        # (slots, nbl, kvh)
-            packed = shard_ctx.constrain(packed, "batch", None, None,
-                                         "kv_heads", "head_dim")
-            dense = kvq.load_block(packed, e, bits)
-            dense = dense.reshape(slots, seq, kvh, hd)
-            return shard_ctx.constrain(dense, "batch", None,
-                                       "kv_heads", "head_dim")
-
-        return qview(cache.k, cache.k_exp), qview(cache.v, cache.v_exp)
-
-    def view(pool):
-        dense = pool[table]
-        dense = shard_ctx.constrain(dense, "batch", None, None,
-                                    "kv_heads", "head_dim")
-        dense = dense.reshape(slots, seq, kvh, hd)
+    def view(pool, exp):
+        dense = pool[table]                       # (slots, nbl, kvh, bs, hd')
+        dense = shard_ctx.constrain(dense, "batch", None, "kv_heads", None,
+                                    "head_dim")
+        if quantized:
+            dense = kvq.load_block(dense, exp[table], cache.bits)
+        dense = dense.transpose(0, 1, 3, 2, 4).reshape(slots, seq, kvh, -1)
         return shard_ctx.constrain(dense, "batch", None,
                                    "kv_heads", "head_dim")
 
-    return view(cache.k), view(cache.v)
+    if quantized:
+        return view(cache.k, cache.k_exp), view(cache.v, cache.v_exp)
+    return view(cache.k, None), view(cache.v, None)
 
 
 class AttnQuant(NamedTuple):
@@ -422,7 +410,7 @@ def paged_prefill_update(cache: AnyPagedKVCache, k_new: jax.Array,
     f32 K/V — identical for every schedule that runs the chunk (the prefix
     cache's bit-exactness relies on this).
     """
-    block_size = cache.k.shape[1]
+    block_size = cache.k.shape[2]
     b, chunk = k_new.shape[0], k_new.shape[1]
     assert chunk % block_size == 0, (chunk, block_size)
     quantized = isinstance(cache, QuantPagedKVCache)
@@ -434,6 +422,8 @@ def paged_prefill_update(cache: AnyPagedKVCache, k_new: jax.Array,
         for j in range(chunk // block_size):
             blk = st.block_table[i, base + j]
             sl = slice(j * block_size, (j + 1) * block_size)
+            kb = k_new[i, sl].swapaxes(0, 1)          # (kvh, bs, hd)
+            vb = v_new[i, sl].swapaxes(0, 1)
             if quantized:
                 valid = None
                 if st.ctx is not None:
@@ -444,19 +434,17 @@ def paged_prefill_update(cache: AnyPagedKVCache, k_new: jax.Array,
                     pos = (st.length[i] + j * block_size
                            + jnp.arange(block_size))
                     valid = pos < st.ctx[i]
-                kb, ke = kvq.store_block(k_new[i, sl], cache.bits,
-                                         valid=valid)
-                vb, ve = kvq.store_block(v_new[i, sl], cache.bits,
-                                         valid=valid)
+                kb, ke = kvq.store_block(kb, cache.bits, valid=valid)
+                vb, ve = kvq.store_block(vb, cache.bits, valid=valid)
                 k = jax.lax.dynamic_update_slice(k, kb[None], (blk, 0, 0, 0))
                 v = jax.lax.dynamic_update_slice(v, vb[None], (blk, 0, 0, 0))
                 k_exp = jax.lax.dynamic_update_slice(k_exp, ke[None], (blk, 0))
                 v_exp = jax.lax.dynamic_update_slice(v_exp, ve[None], (blk, 0))
                 continue
-            kb = k_new[i, sl][None].astype(k.dtype)    # (1, bs, kvh, hd)
-            vb = v_new[i, sl][None].astype(v.dtype)
-            k = jax.lax.dynamic_update_slice(k, kb, (blk, 0, 0, 0))
-            v = jax.lax.dynamic_update_slice(v, vb, (blk, 0, 0, 0))
+            k = jax.lax.dynamic_update_slice(k, kb[None].astype(k.dtype),
+                                             (blk, 0, 0, 0))
+            v = jax.lax.dynamic_update_slice(v, vb[None].astype(v.dtype),
+                                             (blk, 0, 0, 0))
     if quantized:
         return QuantPagedKVCache(k, v, k_exp, v_exp, bits=cache.bits)
     return PagedKVCache(k, v)

@@ -75,7 +75,7 @@ def packed_head_dim(head_dim: int, bits: int) -> int:
 
 
 def store_block(x: jax.Array, bits: int, valid=None):
-    """Quantize one full pool block (positions, kv_heads, head_dim) -> packed
+    """Quantize one full pool block (kv_heads, positions, head_dim) -> packed
     payload + per-head exponent.  The whole-block write path (prefill chunks,
     which always cover complete blocks on the absolute chunk grid).
 
@@ -88,17 +88,18 @@ def store_block(x: jax.Array, bits: int, valid=None):
     """
     ax = jnp.abs(x.astype(jnp.float32))
     if valid is not None:
-        ax = jnp.where(valid[..., None, None], ax, 0.0)
-    amax = jnp.max(ax, axis=(-3, -1))                              # (... kvh)
+        ax = jnp.where(valid[..., None], ax, 0.0)
+    amax = jnp.max(ax, axis=(-2, -1))                              # (... kvh)
     e = pot_exponent(amax, bits)
-    q = quantize_pot(x, e[..., None, :, None], bits)
+    q = quantize_pot(x, e[..., None, None], bits)
     return pack_int4(q) if bits == 4 else q, e
 
 
 def load_block(payload: jax.Array, e: jax.Array, bits: int) -> jax.Array:
-    """Inverse of store_block: packed payload + exponent -> f32 block.
+    """Inverse of store_block: packed (..., kv_heads, positions, hd')
+    payload + (..., kv_heads) exponents -> f32 block.
     Shared verbatim by the gather fallback, the jnp oracle, and (via
     unpack_int4/dequantize_pot on refs) the Pallas kernel, so every reader
     dequantizes bit-identically."""
     q = unpack_int4(payload) if bits == 4 else payload
-    return dequantize_pot(q, e[..., None, :, None])
+    return dequantize_pot(q, e[..., None, None])

@@ -214,7 +214,8 @@ def active_impl() -> str:
 def matmul(x: jax.Array, w: Any) -> jax.Array:
     """``x @ w`` where ``w`` may be a packed 2-D weight.
 
-    Kernel path (TPU, or forced via use_impl): tiles DMA'd packed into VMEM
+    Kernel path (TPU, or forced via use_impl; a forced "kernel" off the TPU
+    raises rather than interpreting): tiles DMA'd packed into VMEM
     and dequantized per k-tile inside the Pallas matmul.  Everywhere else —
     raw arrays, >2-D projections, mesh/CPU runs — the dense fallback keeps
     results exact.
@@ -223,10 +224,13 @@ def matmul(x: jax.Array, w: Any) -> jax.Array:
         return x @ w
     impl = active_impl()
     if impl != "dense" and w.q.ndim == 2 and w.caxis == -2:
+        if impl == "kernel" and jax.default_backend() != "tpu":
+            raise RuntimeError(
+                "weight-matmul impl 'kernel' needs a TPU backend, found "
+                f"{jax.default_backend()!r}; use 'kernel_interpret' to run "
+                "the kernel in the Pallas interpreter")
         from repro.kernels import ops
-        return ops.matmul_wq(
-            x, w, interpret=(impl == "kernel_interpret"
-                             or jax.default_backend() != "tpu"))
+        return ops.matmul_wq(x, w, interpret=(impl == "kernel_interpret"))
     return x @ dense(w)
 
 

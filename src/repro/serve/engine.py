@@ -81,7 +81,9 @@ Cache backends:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
+import re
 import time
 from collections import Counter, deque
 from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
@@ -261,7 +263,8 @@ class _CountingJit:
     an evicted entry leaves unchanged.
     """
 
-    def __init__(self, fn, name: str, donate_argnums=(), on_trace=None):
+    def __init__(self, fn, name: str, donate_argnums=(), on_trace=None,
+                 out_shardings=None):
         self.name = name
         self._traces = 0
 
@@ -273,7 +276,8 @@ class _CountingJit:
                 on_trace()
             return fn(*args)
 
-        self._jit = jax.jit(counted, donate_argnums=donate_argnums)
+        kw = {} if out_shardings is None else {"out_shardings": out_shardings}
+        self._jit = jax.jit(counted, donate_argnums=donate_argnums, **kw)
 
     def __call__(self, *args):
         return self._jit(*args)
@@ -471,8 +475,11 @@ class ServeEngine:
             # as static pytree aux, so every jitted step below traces once
             # per shape exactly as with raw float params — zero extra
             # compiles at any width
-            self.params = wq_lib.pack_params(self.params, cfg,
-                                             self.precision)
+            # (one jitted program: eager packing of a published-width
+            # vocab table would hold several f32 copies of it at once)
+            self.params = jax.jit(functools.partial(
+                wq_lib.pack_params, cfg=cfg, policy=self.precision))(
+                    self.params)
 
         if mesh is not None:
             from repro.serve import sharding as shard_lib
@@ -558,6 +565,7 @@ class ServeEngine:
         self._last_audit_tick = 0
         self._health = HEALTHY
         self.health_reason = ""
+        self.errors: deque = deque(maxlen=32)   # contained exceptions
         self._has_deadlines = False   # sticky: set by the first deadline
         # submit, so deadline-free serving never scans for expiry
         if ecfg.watchdog_recovery < 1:
@@ -581,12 +589,19 @@ class ServeEngine:
         # buffers in place instead of copying the KV pool per decoded token
         decode_fn, prefill_fn, reset_fn, chunk_fn = (
             self._decode_fn, self._prefill_fn, self._reset_fn, self._chunk_fn)
+        cache_sh = state_sh = None
         if mesh is not None:
             # activation-sharding constraints must be live while these trace
             from repro.serve import sharding as shard_lib
             decode_fn = shard_lib.with_shard_ctx(decode_fn, mesh, cfg)
             prefill_fn = shard_lib.with_shard_ctx(prefill_fn, mesh, cfg)
             chunk_fn = shard_lib.with_shard_ctx(chunk_fn, mesh, cfg)
+            # the donated trees leave every program in the placement they
+            # were built with, so each program has one input signature and
+            # warmup() traces exactly the signatures serving calls
+            self._state = shard_lib.place_slot_state(self._state, mesh)
+            cache_sh = jax.tree.map(lambda x: x.sharding, self.caches)
+            state_sh = jax.tree.map(lambda x: x.sharding, self._state)
         def on_trace(name):
             # per-fn compile events into the registry; _CountingJit._traces
             # stays the authoritative count for compile_count()
@@ -594,29 +609,38 @@ class ServeEngine:
                 return None
             return self._tel.jit_traces.labels(fn=name).inc
 
-        self._decode = _CountingJit(decode_fn, "decode",
-                                    donate_argnums=(1, 2),
-                                    on_trace=on_trace("decode"))
+        # the bodies as jitted (shard ctx included), for kernel_names()
+        self._step_fns = {"decode": decode_fn, "prefill_chunk": chunk_fn}
+        self._decode = _CountingJit(
+            decode_fn, "decode", donate_argnums=(1, 2),
+            on_trace=on_trace("decode"),
+            out_shardings=(None if cache_sh is None
+                           else (cache_sh, state_sh, None, None, None)))
         self._prefill = _CountingJit(prefill_fn, "prefill",
                                      donate_argnums=(3,),
-                                     on_trace=on_trace("prefill"))
+                                     on_trace=on_trace("prefill"),
+                                     out_shardings=cache_sh)
         self._reset = _CountingJit(reset_fn, "reset_slot",
                                    donate_argnums=(0,),
-                                   on_trace=on_trace("reset_slot"))
+                                   on_trace=on_trace("reset_slot"),
+                                   out_shardings=cache_sh)
         # chunked-prefill chunk forward + the copy-on-write block copy
         # (partial-block prefix reuse); paged engines only
         self._chunk = _CountingJit(chunk_fn, "prefill_chunk",
                                    donate_argnums=(2,),
-                                   on_trace=on_trace("prefill_chunk"))
+                                   on_trace=on_trace("prefill_chunk"),
+                                   out_shardings=cache_sh)
         self._copy = _CountingJit(self._copy_fn, "cow_copy",
                                   donate_argnums=(0,),
-                                  on_trace=on_trace("cow_copy"))
+                                  on_trace=on_trace("cow_copy"),
+                                  out_shardings=cache_sh)
         # numeric quarantine: zero a possibly-poisoned pool block before it
         # returns to the allocator (paged only; warmed alongside cow_copy so
         # fault handling never adds a trace)
         self._scrub = _CountingJit(self._scrub_fn, "scrub_block",
                                    donate_argnums=(0,),
-                                   on_trace=on_trace("scrub_block"))
+                                   on_trace=on_trace("scrub_block"),
+                                   out_shardings=cache_sh)
         self._jits = (self._decode, self._prefill, self._reset, self._chunk,
                       self._copy, self._scrub)
 
@@ -850,6 +874,15 @@ class ServeEngine:
         self.trace.record(-1, "health", state=state, reason=reason)
         if self._tel is not None:
             self._tel.health.set(_HEALTH_CODE[state])
+
+    def record_error(self, exc: BaseException,
+                     rid: Optional[int] = None) -> str:
+        """Keep a contained exception visible: its type and message go into
+        metrics()["errors"] (the newest 32) and are returned for the trace
+        event of whatever the containment retires or degrades."""
+        text = f"{type(exc).__name__}: {exc}"
+        self.errors.append({"rid": rid, "error": text})
+        return text
 
     def mark_degraded(self, reason: str) -> None:
         """Degrade the engine (front-door tick-loop containment, operator
@@ -1312,13 +1345,15 @@ class ServeEngine:
                     continue
                 try:
                     self._run_chunk(rs)
-                except Exception:
+                except Exception as e:
                     # chunk-level fault containment: one failed chunk costs
                     # one request ("internal_error"), never the engine —
                     # _retire frees its blocks and unpins its published
-                    # chain; co-prefilling slots keep their grants
+                    # chain; co-prefilling slots keep their grants. The
+                    # exception (a compile error, say) is kept, not dropped
                     self._retire(slot, rs, "internal_error",
-                                 time.perf_counter(), self.stats["ticks"])
+                                 time.perf_counter(), self.stats["ticks"],
+                                 error=self.record_error(e, rs.rid))
                     continue
                 budget -= C
                 ran += 1
@@ -1336,13 +1371,14 @@ class ServeEngine:
         return ran
 
     def _retire(self, slot: int, rs: RequestState, reason: str,
-                now: float, tick: int) -> None:
+                now: float, tick: int, error: Optional[str] = None) -> None:
         self.scheduler.retire(rs, tick, now, reason)
         decode_s = (now - rs.first_token_time
                     if rs.first_token_time is not None else 0.0)
         self.trace.record(rs.rid, "finish", reason=reason,
                           tokens=len(rs.out_tokens), decode_s=decode_s,
-                          tpot_s=rs.tpot or 0.0)
+                          tpot_s=rs.tpot or 0.0,
+                          **({"error": error} if error else {}))
         self.slot_req[slot] = None
         self._host_len[slot] = 0
         if self.paged:
@@ -2278,6 +2314,30 @@ class ServeEngine:
                 "param_bytes": t.param_bytes,
                 "param_bytes_by_dtype": dict(t.param_bytes_by_dtype)}
 
+    def kernel_names(self) -> Dict[str, List[str]]:
+        """Pallas kernels compiled into the decode step (largest bucket)
+        and, on the paged backend, the chunked-prefill step, read from
+        their lowering: nothing runs and nothing is traced into the
+        engine's counted jits.  An interpreted kernel lowers to plain HLO
+        and never shows up here, so on a TPU this names the kernels that
+        really run."""
+        def names(fn, *args) -> List[str]:
+            text = jax.jit(fn).lower(*args).as_text()
+            return sorted(set(re.findall(r"kernel_name\W+(\w+)", text)))
+
+        bt = (self.block_table[:, :self.decode_buckets[-1]] if self.paged
+              else None)
+        out = {"decode": names(self._step_fns["decode"], self.params,
+                               self.caches, self._state, bt,
+                               self._sp_packed, self._key)}
+        if self.paged:
+            out["prefill_chunk"] = names(
+                self._step_fns["prefill_chunk"], self.params,
+                np.zeros((1, self.prefill_chunk), np.int32), self.caches,
+                np.zeros((1, self.chunk_widths[0]), np.int32),
+                np.zeros(1, np.int32), np.zeros(1, np.int32))
+        return out
+
     def metrics(self) -> Dict[str, Any]:
         """Snapshot of the engine's serving metrics (merged over the
         scheduler's lifecycle aggregates). Side-effect-free and cheap: the
@@ -2293,6 +2353,7 @@ class ServeEngine:
         m["compiles"] = self.compile_count()
         m["compiles_by_fn"] = {j.name: j.compiles for j in self._jits}
         m["health"] = self._health
+        m["errors"] = list(self.errors)
         m["faults_injected"] = (dict(self.faults.injected)
                                 if self.faults is not None else {})
         # prefix-cache counters are always present (zero when disabled) so

@@ -316,6 +316,7 @@ class FrontDoor:
                 # (submit() starts refusing) but the loop keeps running —
                 # in-flight streams drain to completion instead of hanging
                 # their consumers on a dead tick task
+                eng.record_error(e)
                 eng.mark_degraded(f"tick_error:{type(e).__name__}")
                 try:
                     eng.drain()

@@ -273,17 +273,17 @@ def init_paged_caches(cfg: ModelConfig, num_blocks: int, block_size: int, *,
                                   layer=f"group{gi}.l{li}")
             if bits == 16:
                 per_layer.append(PagedKVCache(
-                    k=jnp.zeros((repeats, num_blocks, block_size, kvh, hd),
+                    k=jnp.zeros((repeats, num_blocks, kvh, block_size, hd),
                                 dtype),
-                    v=jnp.zeros((repeats, num_blocks, block_size, kvh, hd),
+                    v=jnp.zeros((repeats, num_blocks, kvh, block_size, hd),
                                 dtype),
                 ))
                 continue
             hdp = kvq.packed_head_dim(hd, bits)
             per_layer.append(QuantPagedKVCache(
-                k=jnp.zeros((repeats, num_blocks, block_size, kvh, hdp),
+                k=jnp.zeros((repeats, num_blocks, kvh, block_size, hdp),
                             jnp.int8),
-                v=jnp.zeros((repeats, num_blocks, block_size, kvh, hdp),
+                v=jnp.zeros((repeats, num_blocks, kvh, block_size, hdp),
                             jnp.int8),
                 k_exp=jnp.full((repeats, num_blocks, kvh), kvq.EXP_EMPTY,
                                jnp.int8),

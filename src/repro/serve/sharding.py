@@ -17,9 +17,12 @@ The serving scheme differs from training's FSDP x TP (launch/steps.py):
   * dense caches — launch/steps.cache_pspecs: slot batch over `data`,
     kv heads over `model`.
   * slot state (last token, lengths, decode budget, active mask) — a tiny
-    device-resident tree donated through the decode jit each tick; the
-    sampler batch and PRNG key ride in uncommitted, and the embed-lookup
-    constraint re-shards the token batch over `data` on entry to the model.
+    device-resident tree donated through the decode jit each tick, placed
+    slot-over-`data` at construction (place_slot_state); the engine pins
+    every step's donated outputs to their construction placement, so no
+    program ever sees a second input placement.  The sampler batch and
+    PRNG key ride in uncommitted, and the embed-lookup constraint
+    re-shards the token batch over `data` on entry to the model.
   * paged decode impl — under a mesh the engine uses the dense-gather path
     (the Pallas paged-attention kernel has no GSPMD partitioning rule, so
     the engine rejects an explicit kernel+mesh combination; sharding it via
@@ -46,13 +49,15 @@ from typing import Optional
 
 import numpy as np
 import jax
-from jax.sharding import Mesh, PartitionSpec as P
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.launch import steps as steps_lib
 # make_serve_mesh/parse_mesh_spec re-exported so engine callers can build
 # meshes without touching launch/
 from repro.launch.mesh import (make_serve_mesh, named_shardings,  # noqa: F401
                                parse_mesh_spec)
+from repro.models import lm
 from repro.models.config import ModelConfig
 from repro.nn.attention import PagedKVCache, QuantPagedKVCache
 from repro.quant.weights import QuantWeight
@@ -111,6 +116,21 @@ def _wq_leaf_spec(spec, w: QuantWeight, mesh: Mesh):
     return dataclasses.replace(w, q=P(*entries), e=P(*e_entries))
 
 
+def init_params(cfg: ModelConfig, key: jax.Array, *, dtype=jnp.float32,
+                mesh: Optional[Mesh] = None):
+    """Seeded random parameters, built by one jitted program straight into
+    their serving placement: tensor-parallel shards under `mesh` (no device
+    ever holds the whole tree — a published-width model can be larger than
+    one chip), the default device otherwise."""
+    def init(k):
+        return lm.init_lm(cfg, k, dtype=dtype)[0]
+
+    if mesh is None:
+        return jax.jit(init)(key)
+    _, pspecs = steps_lib.param_pspecs(cfg, mesh, fsdp=False)
+    return jax.jit(init, out_shardings=named_shardings(mesh, pspecs))(key)
+
+
 def place_params(params, cfg: ModelConfig, mesh: Mesh):
     """Tensor-parallel placement (no FSDP): returns the committed param tree.
 
@@ -136,12 +156,12 @@ def place_dense_caches(caches, cfg: ModelConfig, mesh: Mesh, slots: int):
 
 
 def _pool_leaf_spec(mesh: Mesh, kv_heads: int, packed_hd: int):
-    """Payload spec for one (repeats, blocks, block_size, kvh, hd') leaf:
+    """Payload spec for one (repeats, blocks, kvh, block_size, hd') leaf:
     kv heads shard over `model` when divisible (the *packed* head_dim as the
     fallback, matching cache_pspecs), blocks stay whole on every replica."""
     m = _axis_size(mesh, "model")
     if kv_heads % m == 0:
-        return P(None, None, None, "model", None)
+        return P(None, None, "model", None, None)
     if packed_hd % m == 0:
         return P(None, None, None, None, "model")
     return P(None, None, None, None, None)
@@ -162,7 +182,7 @@ def paged_pool_pspecs(cfg: ModelConfig, mesh: Mesh, pools=None):
 
     def leaf_spec(c):
         if isinstance(c, QuantPagedKVCache):
-            spec = _pool_leaf_spec(mesh, c.k.shape[-2], c.k.shape[-1])
+            spec = _pool_leaf_spec(mesh, c.k.shape[-3], c.k.shape[-1])
             espec = (P(None, None, "model") if c.k_exp.shape[-1] % m == 0
                      else P(None, None, None))
             return QuantPagedKVCache(spec, spec, espec, espec, bits=c.bits)
@@ -181,6 +201,15 @@ def paged_pool_pspecs(cfg: ModelConfig, mesh: Mesh, pools=None):
 def place_paged_pools(pools, cfg: ModelConfig, mesh: Mesh):
     return jax.device_put(
         pools, named_shardings(mesh, paged_pool_pspecs(cfg, mesh, pools)))
+
+
+def place_slot_state(state, mesh: Mesh):
+    """Per-slot decode state: the slot axis over `data`, as the decode
+    activations shard the slot batch (replicated when slots don't divide)."""
+    d = _axis_size(mesh, "data")
+    return jax.tree.map(
+        lambda x: jax.device_put(x, NamedSharding(
+            mesh, P("data") if x.shape[0] % d == 0 else P())), state)
 
 
 def mesh_summary(mesh: Mesh) -> str:

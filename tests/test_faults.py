@@ -419,7 +419,37 @@ def test_chunk_error_contained_to_target(small_lm):
     assert fin[1] == "internal_error"
     assert all(fin[r] in ("eos", "max_tokens") for r in (0, 2, 3))
     assert len(done) == 4
+    # the contained exception stays visible: metrics and the finish event
+    err = "InjectedFault: injected fault site=chunk_error rid=1 tick="
+    [rec] = engine.metrics()["errors"]
+    assert rec["rid"] == 1 and rec["error"].startswith(err)
+    [ev] = [e for e in engine.trace.events()
+            if e["event"] == "finish" and e["rid"] == 1]
+    assert ev["error"] == rec["error"]
     assert engine.allocator.free_blocks == engine.allocator.num_blocks - 1
+
+
+def test_serving_failures_flags_internal_error_and_degraded(small_lm):
+    """The launcher's exit status: a request retired by containment, or an
+    engine left degraded, fails the run (launch/serve.serving_failures)."""
+    from repro.launch.serve import serve_requests, serving_failures
+    cfg, params = small_lm
+    clean = ServeEngine(cfg, params,
+                        EngineConfig(slots=2, max_seq=64, page_size=8))
+    done = serve_requests(clean, make_requests(cfg, 3, max_new=4))
+    assert len(done) == 3 and serving_failures(clean) == []
+    clean.mark_degraded("operator")
+    assert serving_failures(clean) == ["engine degraded: operator"]
+
+    plan = fl.FaultPlan()
+    plan.arm("chunk_error", rid=1)
+    engine = ServeEngine(cfg, params,
+                         EngineConfig(slots=2, max_seq=64, page_size=8,
+                                      faults=plan))
+    assert len(serve_requests(engine, make_requests(cfg, 3, max_new=4))) == 3
+    [failure] = serving_failures(engine)
+    assert "1 request(s) retired as internal_error (rids [1])" in failure
+    assert "InjectedFault: injected fault site=chunk_error" in failure
 
 
 def test_step_error_contained_and_run_completes(small_lm):
@@ -610,6 +640,8 @@ def test_frontdoor_tick_error_degrades_but_streams_drain(small_lm):
             while engine.health == HEALTHY:
                 await asyncio.sleep(0.002)
             assert engine.health_reason == "tick_error:RuntimeError"
+            assert engine.metrics()["errors"] == [
+                {"rid": None, "error": "RuntimeError: transient device error"}]
             with pytest.raises(EngineUnhealthy):
                 await door.submit(np.array([5], np.int32),
                                   max_new_tokens=2)

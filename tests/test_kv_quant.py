@@ -53,8 +53,8 @@ def _serve(engine, cfg, *, n=5, max_new=6, seed=0):
 def _quant_pool(rng, *, nb, kvh, hd, bits):
     hdp = kvq.packed_head_dim(hd, bits)
     return QuantPagedKVCache(
-        k=jnp.zeros((nb, BS, kvh, hdp), jnp.int8),
-        v=jnp.zeros((nb, BS, kvh, hdp), jnp.int8),
+        k=jnp.zeros((nb, kvh, BS, hdp), jnp.int8),
+        v=jnp.zeros((nb, kvh, BS, hdp), jnp.int8),
         k_exp=jnp.full((nb, kvh), kvq.EXP_EMPTY, jnp.int8),
         v_exp=jnp.full((nb, kvh), kvq.EXP_EMPTY, jnp.int8), bits=bits)
 

@@ -161,3 +161,17 @@ def test_param_counts_in_expected_range():
         shapes, _ = abstract_params(get_config(arch))
         n = sum(math.prod(s.shape) for s in jax.tree.leaves(shapes)) / 1e9
         assert lo <= n <= hi, f"{arch}: {n:.2f}B not in [{lo},{hi}]"
+
+
+def test_depth_cut_keeps_widths():
+    """`layers` cuts depth only (whole periods); widths never change."""
+    full = get_config("glm4-9b")
+    cut = get_config("glm4-9b", layers=4)
+    assert cut.num_layers == 4
+    assert cut.replace(groups=full.groups) == full
+    assert get_config("jamba-v0.1-52b", layers=16).num_layers == 16
+    for bad in (0, 41):
+        with pytest.raises(ValueError, match="outside"):
+            get_config("glm4-9b", layers=bad)
+    with pytest.raises(ValueError, match="whole layer period"):
+        get_config("jamba-v0.1-52b", layers=12)

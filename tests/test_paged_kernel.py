@@ -45,9 +45,9 @@ def make_table(rng, lengths, nblocks, num_blocks, *, poison=None, pools=None):
 def rand_case(rng, *, slots, h, kvh, d, nblocks, num_blocks, lengths,
               poison=None):
     q = jnp.asarray(rng.normal(size=(slots, h, d)), jnp.float32)
-    k_pool = jnp.asarray(rng.normal(size=(num_blocks, BS, kvh, d)),
+    k_pool = jnp.asarray(rng.normal(size=(num_blocks, kvh, BS, d)),
                          jnp.float32)
-    v_pool = jnp.asarray(rng.normal(size=(num_blocks, BS, kvh, d)),
+    v_pool = jnp.asarray(rng.normal(size=(num_blocks, kvh, BS, d)),
                          jnp.float32)
     table, pools = make_table(rng, lengths, nblocks, num_blocks,
                               poison=poison, pools=(k_pool, v_pool))
@@ -109,8 +109,8 @@ def test_kernel_free_then_reuse_blocks(rng):
     table[0, :2] = b
     table[1, :4] = c
     q = jnp.asarray(rng.normal(size=(2, 4, 16)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(16, BS, 2, 16)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(16, BS, 2, 16)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(16, 2, BS, 16)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(16, 2, BS, 16)), jnp.float32)
     got = paged_attention(q, kp, vp, jnp.asarray(table), jnp.asarray(lengths))
     want = paged_attention_ref(q, kp, vp, jnp.asarray(table),
                                jnp.asarray(lengths))
@@ -166,7 +166,7 @@ def test_paged_decode_attention_wrapper_kernel_vs_gather(rng):
 
 
 def test_paged_view_max_blocks_is_a_prefix_gather(rng):
-    kp = jnp.asarray(rng.normal(size=(12, BS, 2, 8)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(12, 2, BS, 8)), jnp.float32)
     cache = attn_lib.PagedKVCache(k=kp, v=kp)
     bt = jnp.asarray(rng.integers(0, 12, size=(3, 4)).astype(np.int32))
     st = attn_lib.PagedState(bt, jnp.zeros(3, jnp.int32))
@@ -182,8 +182,8 @@ def test_paged_view_max_blocks_is_a_prefix_gather(rng):
 
 def mq_case(rng, *, b, chunk, h, kvh, d, nblocks, num_blocks, starts):
     q = jnp.asarray(rng.normal(size=(b, chunk, h, d)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(num_blocks, BS, kvh, d)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(num_blocks, BS, kvh, d)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(num_blocks, kvh, BS, d)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(num_blocks, kvh, BS, d)), jnp.float32)
     bt = jnp.asarray(rng.integers(1, num_blocks,
                                   size=(b, nblocks)).astype(np.int32))
     return q, kp, vp, bt, jnp.asarray(np.asarray(starts, np.int32))
@@ -361,6 +361,23 @@ def test_engine_kernel_impl_matches_gather(tiny_lm):
         out[impl] = _serve(engine, cfg)
         assert engine.compile_count() == warm   # kernel path is static too
     assert out["kernel"] == out["gather"]
+
+
+def test_kernel_names_lists_only_compiled_kernels(tiny_lm):
+    """kernel_names() reads the Pallas custom calls of the serving programs:
+    an interpreted kernel lowers to plain HLO and is never reported (on a
+    TPU the same call names paged_attention / matmul_wq), and reading them
+    adds no trace to the engine's counted jits."""
+    cfg, params = tiny_lm
+    engine = ServeEngine(cfg, params,
+                         EngineConfig(slots=2, max_seq=64, page_size=8,
+                                      paged_impl="kernel"))
+    if jax.default_backend() != "tpu":
+        assert engine.kernel_names() == {"decode": [],
+                                         "prefill_chunk": []}
+    else:
+        assert "paged_attention" in engine.kernel_names()["decode"]
+    assert engine.compile_count() == 0
 
 
 def test_engine_attn_grau_epilogue_matches_across_impls(tiny_lm):

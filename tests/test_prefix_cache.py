@@ -273,9 +273,10 @@ def _slot_prefix_views(engine, slot, ctx):
     row = engine.block_table[slot, :engine.blocks_per_slot]
     views = []
     for leaf in jax.tree.leaves(engine.caches):
-        arr = np.asarray(leaf)                       # (reps, nb, bs, kvh, hd)
-        reps, _, bs, kvh, hd = arr.shape
-        dense = arr[:, row].reshape(reps, -1, kvh, hd)
+        arr = np.asarray(leaf)                       # (reps, nb, kvh, bs, hd)
+        reps, _, kvh, bs, hd = arr.shape
+        dense = (arr[:, row].transpose(0, 1, 3, 2, 4)
+                 .reshape(reps, -1, kvh, hd))
         views.append(dense[:, :ctx])
     return views
 

@@ -64,14 +64,14 @@ def test_build_cell_host_mesh_lowers():
     from repro.configs.archs import get_config
     from repro.configs.shapes import ShapeSpec
     from repro.launch import steps as steps_lib
-    from repro.launch.mesh import named_shardings, use_mesh
+    from repro.launch.mesh import make_mesh, named_shardings
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = get_config("llama3.2-3b", smoke=True)
     shape = ShapeSpec("tiny", 64, 2, "train")
     bundle = steps_lib.build_cell(cfg, shape, mesh, remat="full",
                                   q_chunk=32, kv_chunk=32, dtype=jnp.float32)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(bundle.fn,
                            in_shardings=named_shardings(mesh,
                                                         bundle.in_shardings),
@@ -87,13 +87,13 @@ def test_build_cell_decode_host_mesh():
     from repro.configs.archs import get_config
     from repro.configs.shapes import ShapeSpec
     from repro.launch import steps as steps_lib
-    from repro.launch.mesh import named_shardings, use_mesh
+    from repro.launch.mesh import make_mesh, named_shardings
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = get_config("mamba2-1.3b", smoke=True)
     shape = ShapeSpec("tinydec", 128, 2, "decode")
     bundle = steps_lib.build_cell(cfg, shape, mesh, dtype=jnp.float32)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(bundle.fn,
                            in_shardings=named_shardings(mesh,
                                                         bundle.in_shardings),

@@ -208,14 +208,20 @@ def test_engine_sharded_sampling_state_partitions(params):
     assert sh_toks == base_toks
 
 
-def test_engine_sharded_never_recompiles_after_warmup(params):
+@pytest.mark.parametrize("data,model", [(2, 2), (1, 4), (4, 1)])
+def test_engine_sharded_never_recompiles_after_warmup(data, model, params):
     """The static-shape serving invariant must hold under a mesh too: after
     warmup() traces every decode/prefill bucket, donated caches and slot
-    state cycling through two full traffic waves add zero jit signatures."""
-    mesh = _mesh_or_skip(2, 2)
-    eng = ServeEngine(CFG, params,
-                      EngineConfig(slots=SLOTS, max_seq=MAX_SEQ), mesh=mesh)
+    state cycling through two full traffic waves add zero jit signatures.
+    warmup() traces one signature per program and bucket, as on one device:
+    a second placement of the donated trees (construction vs program
+    output) would show up here as extra warm traces."""
+    mesh = _mesh_or_skip(data, model)
+    ecfg = EngineConfig(slots=SLOTS, max_seq=MAX_SEQ)
+    eng = ServeEngine(CFG, params, ecfg, mesh=mesh)
     warm = eng.warmup()
+    # 3 decode buckets + 2 chunk-table widths + cow_copy + scrub_block
+    assert warm == ServeEngine(CFG, params, ecfg).warmup() == 7
     _mixed_traffic(eng, _requests())
     _mixed_traffic(eng, _requests())
     assert eng.compile_count() == warm
@@ -241,3 +247,20 @@ def test_kernel_engine_matches_sharded_gather_engine(params):
         ServeEngine(CFG, params,
                     EngineConfig(slots=SLOTS, max_seq=MAX_SEQ,
                                  paged_impl="kernel"), mesh=mesh)
+
+
+def test_init_params_built_into_shards(params):
+    """serve/sharding.init_params builds the tree straight into its serving
+    placement — the same values as on one device, each leaf already laid
+    out as place_params would put it."""
+    mesh = _mesh_or_skip(1, 4)
+    key = jax.random.PRNGKey(0)
+    one = shard_lib.init_params(CFG, key)
+    sharded = shard_lib.init_params(CFG, key, mesh=mesh)
+    want = shard_lib.place_params(one, CFG, mesh)
+    for a, b, w in zip(jax.tree.leaves(one), jax.tree.leaves(sharded),
+                       jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert b.sharding.is_equivalent_to(w.sharding, b.ndim)
+    for a, b in zip(jax.tree.leaves(one), jax.tree.leaves(params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

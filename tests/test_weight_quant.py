@@ -222,6 +222,10 @@ def test_matmul_dispatch_impls(rng):
                                   np.asarray(x @ w))
     with pytest.raises(ValueError, match="impl"):
         wq.use_impl("vector").__enter__()
+    # a forced compiled kernel off the TPU fails loudly, never interprets
+    if jax.default_backend() != "tpu":
+        with wq.use_impl("kernel"), pytest.raises(RuntimeError, match="TPU"):
+            wq.matmul(x, qw)
 
 
 # ---------------------------------------------------------------------------
