@@ -247,12 +247,15 @@ def apply_lm(
         aux_total = aux_total + aux
         new_caches.append(ys)
 
-    x = blocks.apply_norm(params, "ln_f", x, cfg.norm, cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", x, wq_lib.dense(params["embed"]))
-    else:
-        logits = jnp.einsum("bsd,dv->bsv", x, wq_lib.dense(params["head"]))
-    logits = shard_ctx.constrain(logits, "batch", "seq", "vocab")
+    with jax.named_scope("head"):
+        x = blocks.apply_norm(params, "ln_f", x, cfg.norm, cfg.norm_eps)
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("bsd,vd->bsv", x,
+                                wq_lib.dense(params["embed"]))
+        else:
+            logits = jnp.einsum("bsd,dv->bsv", x,
+                                wq_lib.dense(params["head"]))
+        logits = shard_ctx.constrain(logits, "batch", "seq", "vocab")
     return logits, (tuple(new_caches) if caches is not None else None), aux_total
 
 

@@ -340,41 +340,42 @@ def apply_layer(
     if spec.cross_attn and cache is not None:
         cache, cross_cache = cache
     x = shard_ctx.constrain(x, "batch", "seq", "embed")
-    h = apply_norm(params, "ln1", x, cfg.norm, cfg.norm_eps)
-    if spec.kind == "attn":
-        p = params["attn"]
-        if mode == "decode":
-            if cfg.mla is not None:
-                a, cache = decode_mla(p, h, cfg, cache=cache)
+    with jax.named_scope("attention" if spec.kind == "attn" else "ssm"):
+        h = apply_norm(params, "ln1", x, cfg.norm, cfg.norm_eps)
+        if spec.kind == "attn":
+            p = params["attn"]
+            if mode == "decode":
+                if cfg.mla is not None:
+                    a, cache = decode_mla(p, h, cfg, cache=cache)
+                else:
+                    a, cache = decode_attention_block(p, h, cfg, cache=cache,
+                                                      paged=paged,
+                                                      paged_impl=paged_impl,
+                                                      attn_quant=attn_quant)
+            elif mode == "prefill" and paged is not None:
+                # chunked prefill into the paged pool (cache is the block pool)
+                a, cache = paged_prefill_attention_block(
+                    p, h, cfg, positions=positions, cache=cache, paged=paged,
+                    paged_impl=paged_impl, attn_quant=attn_quant)
             else:
-                a, cache = decode_attention_block(p, h, cfg, cache=cache,
-                                                  paged=paged,
-                                                  paged_impl=paged_impl,
-                                                  attn_quant=attn_quant)
-        elif mode == "prefill" and paged is not None:
-            # chunked prefill into the paged pool (cache is the block pool)
-            a, cache = paged_prefill_attention_block(
-                p, h, cfg, positions=positions, cache=cache, paged=paged,
-                paged_impl=paged_impl, attn_quant=attn_quant)
+                want_cache = cache if mode == "prefill" else None
+                if cfg.mla is not None:
+                    a, cache = apply_mla(p, h, cfg, positions=positions,
+                                         cache=want_cache, q_chunk=q_chunk,
+                                         kv_chunk=kv_chunk)
+                else:
+                    a, cache = apply_attention(p, h, cfg, positions=positions,
+                                               cache=want_cache, q_chunk=q_chunk,
+                                               kv_chunk=kv_chunk)
         else:
-            want_cache = cache if mode == "prefill" else None
-            if cfg.mla is not None:
-                a, cache = apply_mla(p, h, cfg, positions=positions,
-                                     cache=want_cache, q_chunk=q_chunk,
-                                     kv_chunk=kv_chunk)
+            p = params["mamba"]
+            if mode == "decode":
+                a, cache = decode_mamba2(p, h, cfg.d_model, cfg.ssm, cache)
             else:
-                a, cache = apply_attention(p, h, cfg, positions=positions,
-                                           cache=want_cache, q_chunk=q_chunk,
-                                           kv_chunk=kv_chunk)
-    else:
-        p = params["mamba"]
-        if mode == "decode":
-            a, cache = decode_mamba2(p, h, cfg.d_model, cfg.ssm, cache)
-        else:
-            a, cache = apply_mamba2(p, h, cfg.d_model, cfg.ssm, state=None)
-            if mode != "prefill":
-                cache = None
-    x = x + a
+                a, cache = apply_mamba2(p, h, cfg.d_model, cfg.ssm, state=None)
+                if mode != "prefill":
+                    cache = None
+        x = x + a
 
     if spec.cross_attn:
         h = apply_norm(params, "ln_x", x, cfg.norm, cfg.norm_eps)
@@ -403,16 +404,17 @@ def apply_layer(
         cache = (cache, cross_cache)
 
     if spec.mlp != "none":
-        h = apply_norm(params, "ln2", x, cfg.norm, cfg.norm_eps)
-        if spec.mlp == "moe":
-            # Inference never drops tokens: capacity-factor drops are a
-            # training-time load-balancing discipline, and in decode they
-            # couple co-batched slots (one slot's routing could evict
-            # another's token). Full capacity keeps serving batch-invariant
-            # and prefill/decode consistent.
-            cap = None if mode == "train" else h.shape[0] * h.shape[1]
-            m, aux = apply_moe(params["moe"], h, cfg.moe, act, capacity=cap)
-        else:
-            m = apply_mlp(params["mlp"], h, act, cfg.gated_mlp)
-        x = x + m
+        with jax.named_scope("mlp"):
+            h = apply_norm(params, "ln2", x, cfg.norm, cfg.norm_eps)
+            if spec.mlp == "moe":
+                # Inference never drops tokens: capacity-factor drops are a
+                # training-time load-balancing discipline, and in decode they
+                # couple co-batched slots (one slot's routing could evict
+                # another's token). Full capacity keeps serving batch-invariant
+                # and prefill/decode consistent.
+                cap = None if mode == "train" else h.shape[0] * h.shape[1]
+                m, aux = apply_moe(params["moe"], h, cfg.moe, act, capacity=cap)
+            else:
+                m = apply_mlp(params["mlp"], h, act, cfg.gated_mlp)
+            x = x + m
     return x, cache, aux

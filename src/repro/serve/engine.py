@@ -102,6 +102,7 @@ from repro.quant import weights as wq_lib
 from repro.serve import faults as faults_lib
 from repro.serve import kv_cache as kvc
 from repro.serve import sampling as samp_lib
+from repro.serve import spans as spans_lib
 from repro.serve import telemetry as tel
 from repro.serve import trace as trace_lib
 from repro.serve.sampling import SamplingParams
@@ -261,11 +262,16 @@ class _CountingJit:
     or sharding-driven retraces have identical shapes). The previous
     implementation read the jit cache size, which a retrace that *replaces*
     an evicted entry leaves unchanged.
+
+    Every call opens the host span ``serve.call.<name>`` (a compile lands
+    inside the span of the program that compiled), and the program's ops
+    carry the named scope ``<name>`` in the device trace.
     """
 
     def __init__(self, fn, name: str, donate_argnums=(), on_trace=None,
                  out_shardings=None):
         self.name = name
+        self._span = f"serve.call.{name}"
         self._traces = 0
 
         def counted(*args):
@@ -274,13 +280,15 @@ class _CountingJit:
                 # host-side callback, runs only while tracing (never in the
                 # compiled program): publishes the trace event to telemetry
                 on_trace()
-            return fn(*args)
+            with jax.named_scope(name):
+                return fn(*args)
 
         kw = {} if out_shardings is None else {"out_shardings": out_shardings}
         self._jit = jax.jit(counted, donate_argnums=donate_argnums, **kw)
 
     def __call__(self, *args):
-        return self._jit(*args)
+        with jax.profiler.TraceAnnotation(self._span):
+            return self._jit(*args)
 
     @property
     def compiles(self) -> int:
@@ -342,6 +350,10 @@ class ServeEngine:
             self._tel = None
             self.trace = trace_lib.NullTraceRecorder()
         self.trace.attach_owner(self)
+        # host spans of the tick; with telemetry on they also time the
+        # tick phases
+        self.span = spans_lib.Spans(
+            None if self._tel is None else self._tel.tick_phase)
         self._has_ssm = any(spec.kind == "mamba"
                             for period, _ in cfg.groups for spec in period)
         self.bucketed = not self._has_ssm
@@ -698,11 +710,12 @@ class ServeEngine:
         # per-slot keys from (request stream id, draws so far): sampling is a
         # pure function of the request and its progress, not of when the
         # scheduler happened to run it
-        keys = jax.vmap(
-            lambda s, c: jax.random.fold_in(jax.random.fold_in(key, s), c)
-        )(state.sample_seed, state.sample_step)
         last = logits[:, -1]
-        nxt = samp_lib.sample(last, sp, keys)
+        with jax.named_scope("sample"):
+            keys = jax.vmap(
+                lambda s, c: jax.random.fold_in(jax.random.fold_in(key, s), c)
+            )(state.sample_seed, state.sample_step)
+            nxt = samp_lib.sample(last, sp, keys)
         # numeric guardrail: per-slot finite-logits flag, reduced on device
         # (one (slots,) bool rides the existing drain sync — no extra host
         # round trip, no per-token check). Inactive/ghost slots decode
@@ -1947,7 +1960,8 @@ class ServeEngine:
         if self._has_deadlines:
             self._enforce_deadlines()
         try:
-            n = self._step_impl()
+            with self.span("step"):
+                n = self._step_impl()
         except faults_lib.InjectedFault as e:
             if e.rid is not None and self._retire_anywhere(
                     e.rid, "internal_error"):
@@ -1965,12 +1979,10 @@ class ServeEngine:
         return n
 
     def _step_impl(self) -> int:
-        # tick-phase timing brackets host code the tick already runs —
-        # perf_counter reads at section boundaries, no block_until_ready, no
-        # extra device round trips. The device-step wait itself is observed
-        # in _drain, at the host sync that already exists there.
-        t = self._tel
-        t0 = time.perf_counter() if t is not None else 0.0
+        # the tick phases are spans around host code the tick already runs:
+        # no block_until_ready, no extra device round trips. The device-step
+        # wait itself is spanned in _drain, at the host sync that already
+        # exists there.
         if self.faults is not None:
             spec = self._fault("step_error")
             if spec is not None:
@@ -1978,14 +1990,14 @@ class ServeEngine:
                 # in step() operates on a consistent engine
                 raise faults_lib.InjectedFault("step_error", spec.rid,
                                                self.stats["ticks"])
-        if self.scheduler.waiting and self._health != DRAINING:
+        admit = bool(self.scheduler.waiting) and self._health != DRAINING
+        if admit:
             # admission decisions need an up-to-date view of free slots.
             # (A DRAINING engine stops admitting: queued requests wait —
             # preserved for the final snapshot — while slotted ones finish.)
             self._drain()
-            if t is not None:
-                t0 = time.perf_counter()   # drain timed itself; restart
-            free = self.slot_req.count(None)
+        with self.span("schedule"):
+            free = self.slot_req.count(None) if admit else 0
             if free and self.paged and self.ecfg.preemption:
                 # head blocked on blocks (not slots): evict last-admitted
                 # decode slots so it admits instead of stalling the queue
@@ -2001,33 +2013,31 @@ class ServeEngine:
                 for rs in reversed(not_admitted):
                     self.scheduler.requeue_front(rs)
 
-        if self.paged:
-            # chunked prefill interleaves with decode under the budget;
-            # slots still mid-prefill are excluded from the decode batch
-            self._run_prefill_chunks()
+            if self.paged:
+                # chunked prefill interleaves with decode under the budget;
+                # slots still mid-prefill are excluded from the decode batch
+                self._run_prefill_chunks()
 
-        active = [s for s, r in enumerate(self.slot_req)
-                  if r is not None and not r.pending_chunks]
-        if t is not None:
-            t1 = time.perf_counter()
-            t.phase_schedule.observe(t1 - t0)
+            active = [s for s, r in enumerate(self.slot_req)
+                      if r is not None and not r.pending_chunks]
         if not active:
             return 0
 
-        bt = (self.block_table[:, :self._decode_bucket(active)]
-              if self.paged else None)
-        key = self._key    # per-slot keys are derived inside the decode jit
-        self.caches, self._state, nxt, done, ok = self._decode(
-            self.params, self.caches, self._state, bt, self._sp_packed, key)
-        self._pending.append(_TickRecord(self.stats["ticks"], tuple(active),
-                                         nxt, done, ok))
-        self._host_len[active] += 1
-        self.stats["ticks"] += 1
-        if t is not None:
-            # dispatch = host cost of enqueueing the async decode jit; the
-            # device's own execution time surfaces as _drain's first sync
-            t.phase_dispatch.observe(time.perf_counter() - t1)
-            t.ticks.inc()
+        # dispatch = host cost of enqueueing the async decode jit; the
+        # device's own execution time surfaces as _drain's sync
+        with self.span("dispatch"):
+            bt = (self.block_table[:, :self._decode_bucket(active)]
+                  if self.paged else None)
+            key = self._key    # per-slot keys are derived in the decode jit
+            self.caches, self._state, nxt, done, ok = self._decode(
+                self.params, self.caches, self._state, bt, self._sp_packed,
+                key)
+            self._pending.append(_TickRecord(
+                self.stats["ticks"], tuple(active), nxt, done, ok))
+            self._host_len[active] += 1
+            self.stats["ticks"] += 1
+        if self._tel is not None:
+            self._tel.ticks.inc()
         if len(self._pending) >= self.ecfg.max_pending_ticks:
             self._drain()
         return len(active)
@@ -2049,31 +2059,32 @@ class ServeEngine:
         is admissible at tick t+1 for any caller that polls between steps)."""
         if len(self._pending) <= keep:
             return
+        with self.span("drain"):
+            self._deliver(keep)
+
+    def _deliver(self, keep: int) -> None:
         if keep:
             pending = self._pending[:-keep]
             self._pending = self._pending[-keep:]
         else:
             pending, self._pending = self._pending, []
-        t = self._tel
-        t_start = time.perf_counter() if t is not None else 0.0
-        sync_s = 0.0          # time blocked in the np.asarray host syncs —
-        # the one place the engine already waits on the device, so the
-        # device-step phase is measured without adding any sync of its own
         delivered = 0
         for rec in pending:
-            s0 = time.perf_counter()
-            if self.faults is not None:
-                spec = self._fault("slow_step", tick=rec.tick)
-                if spec is not None:
-                    # a slow/hung device step: the stall lands inside the
-                    # sync bracket below, exactly where a real one would,
-                    # so the watchdog observes it the same way
-                    time.sleep(spec.delay_s)
-            toks = np.asarray(rec.tokens)
-            done = np.asarray(rec.done)
-            oks = np.asarray(rec.ok)
-            now = time.perf_counter()
-            sync_s += now - s0
+            # the np.asarray host syncs are the one place the engine already
+            # waits on the device: the device-step phase adds no sync
+            with self.span("device_step"):
+                s0 = time.perf_counter()
+                if self.faults is not None:
+                    spec = self._fault("slow_step", tick=rec.tick)
+                    if spec is not None:
+                        # a slow/hung device step: the stall lands inside
+                        # the sync bracket, exactly where a real one would,
+                        # so the watchdog observes it the same way
+                        time.sleep(spec.delay_s)
+                toks = np.asarray(rec.tokens)
+                done = np.asarray(rec.done)
+                oks = np.asarray(rec.ok)
+                now = time.perf_counter()
             self._watchdog(now - s0)
             for slot in rec.slots:
                 rs = self.slot_req[slot]
@@ -2122,12 +2133,9 @@ class ServeEngine:
                     reason = ("eos" if tok == self.ecfg.eos_id
                               else "max_tokens")
                     self._retire(slot, rs, reason, now, rec.tick)
-        if t is not None:
+        if self._tel is not None:
             if delivered:
-                t.decode_tokens.inc(delivered)
-            t.phase_device_step.observe(sync_s)
-            t.phase_drain.observe(
-                max(0.0, time.perf_counter() - t_start - sync_s))
+                self._tel.decode_tokens.inc(delivered)
             self._publish_gauges()
 
     def _publish_gauges(self) -> None:

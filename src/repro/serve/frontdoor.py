@@ -35,6 +35,7 @@ from typing import AsyncIterator, List, Optional
 
 import numpy as np
 
+from repro.serve import spans as spans_lib
 from repro.serve.engine import HEALTHY, Request, ServeEngine
 from repro.serve.faults import ProcessCrash
 from repro.serve.sampling import SamplingParams
@@ -289,6 +290,15 @@ class FrontDoor:
                     or any(r is not None for r in eng.slot_req))
 
     async def _run(self) -> None:
+        # full collections are spanned while the tick task runs, and only
+        # then: stop() awaits the task, so no callback outlives the door
+        spans_lib.install_gc_span()
+        try:
+            await self._loop()
+        finally:
+            spans_lib.remove_gc_span()
+
+    async def _loop(self) -> None:
         while self._running:
             # re-read per iteration: handoff() swaps the owned engine while
             # the loop runs, and the next tick must drive the new one
@@ -302,9 +312,11 @@ class FrontDoor:
             # already retired — the newest enqueued tick keeps executing
             # while the host runs delivery and the streams' consumers
             try:
-                eng.step()
-                eng.drain(keep=1)
-                eng.reap()
+                with eng.span("tick"):
+                    eng.step()
+                    eng.drain(keep=1)
+                    with eng.span("reap"):
+                        eng.reap()
             except ProcessCrash:
                 # simulated hard process death: a crashed process cannot
                 # contain its own crash — the tick task dies with it, and

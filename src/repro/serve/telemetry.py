@@ -70,7 +70,7 @@ def percentiles(values: Sequence[float],
 DEFAULT_LATENCY_BUCKETS: Tuple[float, ...] = tuple(
     0.001 * 2 ** i for i in range(18))
 # Tick-phase buckets (seconds): 10us .. ~1.3s. Host scheduling phases are
-# microseconds; the device-step phase is the per-drain compute wait.
+# microseconds; the device-step phase is the wait for one synced tick.
 DEFAULT_TICK_BUCKETS: Tuple[float, ...] = tuple(
     1e-5 * 2 ** i for i in range(18))
 
@@ -512,10 +512,9 @@ class ServingMetrics:
             "Host wall time per tick phase, measured only at host-sync "
             "boundaries that already exist", labels=("phase",),
             buckets=DEFAULT_TICK_BUCKETS)
-        self.phase_schedule = self._tick_phase.labels(phase="schedule")
-        self.phase_dispatch = self._tick_phase.labels(phase="dispatch")
-        self.phase_device_step = self._tick_phase.labels(phase="device_step")
-        self.phase_drain = self._tick_phase.labels(phase="drain")
+        # phase -> child, observed by the engine's spans (serve/spans.py)
+        self.tick_phase = {p: self._tick_phase.labels(phase=p)
+                           for p in TICK_PHASES}
 
 
 # ---------------------------------------------------------------------------
