@@ -589,7 +589,8 @@ class ServeEngine:
         self._tick_window: deque = deque(maxlen=128)
         self._watchdog_arm = 16
         self._watchdog_ok_streak = 0
-        self.stats: Dict[str, Any] = {"ticks": 0, "decode_tokens": 0,
+        self.stats: Dict[str, Any] = {"ticks": 0, "greedy_ticks": 0,
+                                      "decode_tokens": 0,
                                       "prefill_tokens": 0,
                                       "cached_prefix_tokens": 0}
         self._key = jax.random.PRNGKey(ecfg.seed)
@@ -711,11 +712,9 @@ class ServeEngine:
         # pure function of the request and its progress, not of when the
         # scheduler happened to run it
         last = logits[:, -1]
-        with jax.named_scope("sample"):
-            keys = jax.vmap(
-                lambda s, c: jax.random.fold_in(jax.random.fold_in(key, s), c)
-            )(state.sample_seed, state.sample_step)
-            nxt = samp_lib.sample(last, sp, keys)
+        nxt = samp_lib.sample(
+            last, samp_lib.for_active(sp, state.active),
+            samp_lib.SlotKeys(key, state.sample_seed, state.sample_step))
         # numeric guardrail: per-slot finite-logits flag, reduced on device
         # (one (slots,) bool rides the existing drain sync — no extra host
         # round trip, no per-token check). Inactive/ghost slots decode
@@ -2036,8 +2035,14 @@ class ServeEngine:
                 self.stats["ticks"], tuple(active), nxt, done, ok))
             self._host_len[active] += 1
             self.stats["ticks"] += 1
+            # the host's active set holds the device's, so this counts no
+            # more ticks than took the sampler's argmax path
+            greedy = bool(self._sp_packed.greedy[active].all())
+            self.stats["greedy_ticks"] += greedy
         if self._tel is not None:
             self._tel.ticks.inc()
+            if greedy:
+                self._tel.greedy_ticks.inc()
         if len(self._pending) >= self.ecfg.max_pending_ticks:
             self._drain()
         return len(active)

@@ -433,6 +433,10 @@ class ServingMetrics:
         self.prefill_cached = self._prefill_tokens.labels(kind="cached")
         self.ticks = r.counter(
             "serve_ticks_total", "Decode ticks stepped").labels()
+        self.greedy_ticks = r.counter(
+            "serve_greedy_ticks_total",
+            "Decode ticks whose every active slot is greedy (the sampler "
+            "takes the argmax only)").labels()
         self.jit_traces = r.counter(
             "serve_jit_traces_total",
             "jit traces (compilations) per engine function — must not grow "

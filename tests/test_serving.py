@@ -191,6 +191,90 @@ def test_sampler_determinism_fixed_key(small_lm):
     assert any(len(set(toks)) > 1 for toks in a.values())
 
 
+def _sampler_batch(kind):
+    """Four slots of random logits; slot 1 samples top-p unless the batch is
+    all greedy, and is inactive in the batch that says so."""
+    logits = np.random.default_rng(0).normal(size=(4, 64)).astype(np.float32)
+    top_p = SamplingParams(temperature=0.8, top_p=0.9)
+    sp = samp_lib.pack([SamplingParams(),
+                        SamplingParams() if kind == "all_greedy" else top_p,
+                        SamplingParams(), SamplingParams()])
+    active = np.array([True, kind != "inactive_sampler", True, True])
+    keys = samp_lib.SlotKeys(jax.random.PRNGKey(3),
+                             jnp.arange(4, dtype=jnp.int32),
+                             jnp.full((4,), 5, jnp.int32))
+    return logits, sp, active, keys
+
+
+@pytest.mark.parametrize("kind", ["all_greedy", "mixed", "inactive_sampler"])
+def test_sampler_takes_the_path_the_batch_needs(kind):
+    """An all-greedy batch, or one whose only sampling slot is not decoding,
+    gets the argmax; a batch with an active top-p slot gets the full path's
+    tokens in every slot, with the keys the engine folds per slot."""
+    logits, sp, active, keys = _sampler_batch(kind)
+    out = np.asarray(jax.jit(samp_lib.sample)(
+        logits, samp_lib.for_active(sp, active), keys))
+    argmax = logits.argmax(axis=-1)
+    if kind == "mixed":
+        folded = jax.vmap(lambda s, c: jax.random.fold_in(
+            jax.random.fold_in(keys.key, s), c))(keys.seed, keys.step)
+        full = np.asarray(jax.jit(samp_lib._draw)(logits, sp, folded))
+        np.testing.assert_array_equal(out, full)
+        assert out[1] != argmax[1]      # slot 1 drew, it did not argmax
+        np.testing.assert_array_equal(out[[0, 2, 3]], argmax[[0, 2, 3]])
+    else:
+        np.testing.assert_array_equal(out[active], argmax[active])
+
+
+def test_sampler_compiles_one_cond_in_its_scope():
+    """One compiled program holds both paths: a single conditional, and its
+    branches' ops (the argmax's reduce, the full path's sort) carry the
+    ``sample`` scope the decode-step readers look for."""
+    import re
+    logits, sp, _, keys = _sampler_batch("mixed")
+    jaxpr = jax.make_jaxpr(samp_lib.sample)(logits, sp, keys).jaxpr
+    assert [e.primitive.name for e in jaxpr.eqns].count("cond") == 1
+    hlo = jax.jit(samp_lib.sample).lower(logits, sp, keys).compile().as_text()
+    conds = [ln for ln in hlo.splitlines() if " conditional(" in ln]
+    assert len(conds) == 1 and 'op_name="jit(sample)/sample/cond"' in conds[0]
+    paths = [n for n in re.findall(r'op_name="([^"]*)"', hlo) if "/" in n]
+    assert paths and all("sample" in n.split("/") for n in paths)
+    # lax.cond's branch 1 is the argmax (a reduce), branch 0 the full path
+    branch = "jit(sample)/sample/cond/branch_{}_fun/"
+    assert any(n.startswith(branch.format(1)) and n.endswith("/reduce")
+               for n in paths)
+    assert any(n.startswith(branch.format(0)) and n.endswith("/sort")
+               for n in paths)
+
+
+@pytest.mark.parametrize("paged", [True, False])
+def test_greedy_ticks_count_the_argmax_path(small_lm, paged):
+    """stats["greedy_ticks"] counts every tick of a greedy-only run, none of
+    the ticks a sampled request decodes in, and the sampled neighbour does
+    not change the greedy streams' tokens."""
+    cfg, params = small_lm
+    prompts = [np.array([5, 6, 7, 8], np.int32), np.array([9, 4, 3], np.int32)]
+
+    def serve(with_sampled):
+        engine = ServeEngine(cfg, params, EngineConfig(
+            slots=3, max_seq=64, page_size=8, paged=paged))
+        reqs = [Request(rid=i, prompt=p, max_new_tokens=10)
+                for i, p in enumerate(prompts)]
+        if with_sampled:
+            reqs.append(Request(rid=2, prompt=np.array([11, 12], np.int32),
+                                max_new_tokens=4,
+                                sampling=SamplingParams(temperature=0.9,
+                                                        top_p=0.9)))
+        engine.run(reqs)
+        return engine.stats, {r.rid: r.out_tokens for r in reqs[:2]}
+
+    alone, greedy_alone = serve(False)
+    assert alone["greedy_ticks"] == alone["ticks"] > 0
+    mixed, greedy_mixed = serve(True)
+    assert 0 < mixed["greedy_ticks"] <= mixed["ticks"] - 4
+    assert greedy_mixed == greedy_alone
+
+
 def test_engine_isolation(small_lm):
     """A request's output must not depend on its co-batched neighbours."""
     cfg, params = small_lm

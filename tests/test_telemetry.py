@@ -143,6 +143,7 @@ GOLDEN_SCHEMA = {
     "serve_decode_tokens_total": ("counter", ()),
     "serve_prefill_tokens_total": ("counter", ("kind",)),
     "serve_ticks_total": ("counter", ()),
+    "serve_greedy_ticks_total": ("counter", ()),
     "serve_jit_traces_total": ("counter", ("fn",)),
     "serve_prefix_cache_hits_total": ("counter", ()),
     "serve_prefix_cache_misses_total": ("counter", ()),
@@ -357,6 +358,8 @@ def test_registry_counts_match_engine_stats(small_lm):
     snap = engine.registry.snapshot()
     assert snap["serve_decode_tokens_total"] == engine.stats["decode_tokens"]
     assert snap["serve_ticks_total"] == engine.stats["ticks"]
+    # greedy requests only: every tick took the sampler's argmax path
+    assert snap["serve_greedy_ticks_total"] == engine.stats["ticks"]
     assert snap["serve_requests_submitted_total"] == 4
     assert snap["serve_requests_admitted_total"] == 4
     retired = snap["serve_requests_retired_total"]
