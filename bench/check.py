@@ -2,7 +2,8 @@
 
 After the window has closed, a sample of the requests the window finished,
 drawn from the seed and always holding the longest, is run through the plain
-reference (reference.py) once over prompt + served tokens.  For each served
+reference that the configuration names (``cell.reference_module``; the dense
+one is reference.py) once over prompt + served tokens.  For each served
 token the reading is how far its reference logit lies below the reference's
 best logit at that position: 0 where the served token is the reference's
 greedy choice, small where rounding let a near-tie flip, large where a token

@@ -7,9 +7,10 @@ One process does the whole run: it draws the weights on the device from the
 seed, builds the serving engine, warms every program the cell's traffic uses,
 runs the traffic's pre-roll, measures for ``--seconds`` through FrontDoor,
 then frees the engine and checks a sample of the served tokens against the
-plain reference.  With ``--trace 0`` the last line holds the cell's
-end-to-end metrics, with ``--trace 1`` its per-layer metrics, read from a
-profiler trace of the window.  Without a TPU, or with fewer chips than the
+plain reference that the configuration names (``cell.reference_module``).
+With ``--trace 0`` the last line holds the cell's end-to-end metrics, with
+``--trace 1`` its per-layer metrics, read from a profiler trace of the
+window.  Without a TPU, or with fewer chips than the
 cell asks for, it exits 3 and prints no result.
 
 ``--control 1`` puts the control (the reference in bfloat16) in the
@@ -261,7 +262,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     del engine, w
     gc.collect()
 
-    import reference
+    reference = cell.reference_module(conf)
     sample = check.pick(finished, seed)
     r = {}
     if sample:

@@ -9,7 +9,10 @@ layer by layer, the serving engine gets the tree at once.
 Scales keep every sub-layer's output near unit size, so the residual stream
 stays O(1) through the stack and the logits are about N(0, 1): an embedding
 of unit normals, projections at 1/sqrt(fan-in), norm gains 1 + N(0, 0.1^2),
-and QKV biases N(0, 0.25^2) so that the bias path matters.
+and QKV biases N(0, 0.25^2) so that the bias path matters.  An expert's
+projections are scaled by their own contraction axis, not the expert axis
+they are stacked on; the router and the shared experts like any projection,
+so router logits are about N(0, 1) too.
 """
 from __future__ import annotations
 
@@ -29,8 +32,12 @@ def _fan_in(name: str, shape, cfg) -> float:
         return 1.0 / 0.25 ** 2
     if name == "wo":
         return cfg.num_heads * cfg.head_dim
-    if name in ("wq", "wk", "wv", "w_gate", "w_up", "w_down", "head"):
+    if name in ("wq", "wk", "wv", "head", "router", "ws_gate", "ws_up",
+                "ws_down"):
         return shape[0]
+    if name in ("w_gate", "w_up", "w_down"):
+        # (in, out), or (experts, in, out) stacked over a layer's experts
+        return shape[-2]
     raise ValueError(f"no scale for parameter {name!r}")
 
 
@@ -84,14 +91,15 @@ def make(cfg, seed: int, dtype=jnp.float32):
     return jax.jit(build)(root_key(seed))
 
 
-def layer_fn(cfg, dtype=jnp.float32):
-    """A jitted ``f(root, r)``: layer ``r``'s slice of every stacked leaf of
-    `make`'s tree (dense decoders: one group of single-layer periods)."""
-    shapes = tree_shapes(cfg)["group0"]
+def layer_fn(cfg, group: int = 0, dtype=jnp.float32):
+    """A jitted ``f(root, r)``: repetition ``r`` of group `group`'s period,
+    every layer of it (``l0`` ... ``lk``), as the slice of `make`'s stacked
+    leaves."""
+    shapes = tree_shapes(cfg)[f"group{group}"]
 
     def draw(root, r):
         def leaf(kp, s):
-            path = "group0/" + _path(kp)
+            path = f"group{group}/" + _path(kp)
             key = jax.random.fold_in(_leaf_key(root, path), r)
             return _draw(key, path.rsplit("/", 1)[-1], s.shape[1:], cfg,
                          dtype)
